@@ -20,9 +20,13 @@ Gated by ``benchmarks/run.py --smoke``: parity must hold, counts must be
 static, and the fused up/gate pair must cost ONE psum (mlp site = 2 per
 trace on (2, 2): the pair + down; 3 would mean the deferral regressed).
 
-CPU numbers are functional (interpret-mode kernels; the psum runs through
-the same shard_map the TPU path compiles) - the collective *counts* and the
-parity flag are the invariants, the tok/s columns are trend-tracking only.
+The child always runs on the CPU (``JAX_PLATFORMS=cpu``, four forced host
+devices), even on a machine with a TPU: the parent has already used JAX and
+holds the chip, so a child could not reach it anyway.  Its numbers are
+functional - reference GEMMs, with the psums run through the same shard_map
+the TPU path compiles - so the collective *counts* and the parity flag are
+the invariants and the tok/s columns measure the CPU, never a chip.  The
+four-chip measurement of this path is ``chip_smoke.py --four-chips``.
 """
 from __future__ import annotations
 
@@ -41,6 +45,7 @@ _CHILD = """
     os.environ["JAX_PLATFORMS"] = "cpu"
     import json, time
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro import obs
     from repro.configs.base import get_smoke_config
     from repro.core import masks as masks_mod, metrics as metrics_mod
@@ -99,14 +104,15 @@ _CHILD = """
     n_dev = jax.device_count()
     meshes = {}
     for shape in [(1, 4), (2, 2)]:
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_mesh(shape, ("data", "model"))
         r = measure(make_rules(mesh))
         r["tokens_match_replicated"] = r.pop("tokens") == oracle["tokens"]
         r["tok_s_per_device"] = r["tok_s"] / n_dev
         meshes["x".join(map(str, shape))] = r
     oracle.pop("tokens")
     print("BENCH_TP_JSON=" + json.dumps({
-        "devices": n_dev, "arch": cfg.name, "slots": SLOTS,
+        "devices": n_dev, "platform": jax.devices()[0].platform,
+        "arch": cfg.name, "slots": SLOTS,
         "capacity": CAPACITY, "gen_tokens": GEN,
         "replicated": oracle, "meshes": meshes}))
 """
@@ -129,8 +135,9 @@ def tp_bench(out_rows: list) -> dict:
                            for m in result["meshes"].values())
     result["collectives_static"] = all(m["collectives_static"]
                                        for m in result["meshes"].values())
-    print(f"tensor-parallel serve ({result['devices']} forced host devices, "
-          f"{result['arch']}):")
+    print(f"tensor-parallel serve ({result['devices']} forced CPU host "
+          f"devices, {result['arch']}; CPU timings, not a chip "
+          "measurement):")
     print(f"  replicated: {result['replicated']['tok_s']:8.1f} tok/s")
     for name, m in result["meshes"].items():
         psums = m["decode_psums_per_trace"]

@@ -15,8 +15,8 @@ MODEL_FLOPS / (devices * HLO_FLOPs), which exposes remat/redundancy waste.
 
 ``--nm-shard`` prints the shard-local analysis of the K-sharded 2:4 kernel
 (kernels/shard.py): per-device arithmetic intensity, bytes moved, and the
-explicit psum payload against LINK_BW - the decision surface for when
-K-partial accumulation beats a replicated kernel.
+explicit psum payload against the ICI link bandwidth - the decision surface
+for when K-partial accumulation beats a replicated kernel.
 """
 from __future__ import annotations
 
@@ -27,11 +27,27 @@ import pathlib
 import jax
 import jax.numpy as jnp
 
-PEAK_FLOPS = 197e12        # v5e bf16 per chip
-HBM_BW = 819e9             # bytes/s per chip
-LINK_BW = 50e9             # ICI per link
-
 from repro.configs.base import ARCH_IDS, SHAPE_CELLS, get_config
+
+# Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" system architecture:
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect per chip over its 4 ICI links (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_s": 819e9,
+                    "hbm_bytes": 16e9, "ici_link_bytes_s": 1600e9 / 8 / 4},
+}
+# the chip the production dry run lowers for (launch/mesh.py)
+DRYRUN_KIND = "TPU v5 lite"
+
+
+def peaks(kind: str = DRYRUN_KIND) -> dict:
+    """Peak table row for a device kind; a kind with no row is an error,
+    never a silent default."""
+    if kind not in PEAKS:
+        raise KeyError(f"no published peaks for device_kind {kind!r}: add "
+                       "a PEAKS row with its source")
+    return PEAKS[kind]
 
 
 def _param_counts(arch: str) -> tuple[float, float]:
@@ -79,9 +95,10 @@ def analyze_cell(dirpath: pathlib.Path, arch: str, cell: str,
     from repro.launch.hlo_analysis import analyze_file
     s = analyze_file(dirpath / f"{tag}.hlo.gz")
     n_dev = rec["devices"]
-    t_c = s.dot_flops / PEAK_FLOPS
-    t_m = 2.0 * s.bytes_out / HBM_BW
-    t_x = s.coll_bytes / LINK_BW
+    pk = peaks()
+    t_c = s.dot_flops / pk["flops_bf16"]
+    t_m = 2.0 * s.bytes_out / pk["hbm_bytes_s"]
+    t_x = s.coll_bytes / pk["ici_link_bytes_s"]
     dom = max((t_c, "compute"), (t_m, "memory"), (t_x, "collective"))
     mf = model_flops(arch, cell)
     ratio = mf / max(n_dev * s.dot_flops, 1e-30)
@@ -140,9 +157,10 @@ def nm_shard_roofline(M: int, K: int, N: int, *, devices: int = 1,
     out_b = M * N * 4                              # f32 partial write
     bytes_moved = vals_b + idx_b + x_b + out_b
     psum_b = 0.0 if devices == 1 else M * N * 4    # per-device psum payload
-    t_c = flops / PEAK_FLOPS
-    t_m = bytes_moved / HBM_BW
-    t_x = psum_b / LINK_BW
+    pk = peaks()
+    t_c = flops / pk["flops_bf16"]
+    t_m = bytes_moved / pk["hbm_bytes_s"]
+    t_x = psum_b / pk["ici_link_bytes_s"]
     dom = max((t_c, "compute"), (t_m, "memory"), (t_x, "collective"))
     return {
         "M": M, "K": K, "N": N, "devices": devices, "idx_bits": idx_bits,

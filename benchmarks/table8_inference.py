@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import fmt_row
+from benchmarks.roofline import peaks
 from repro.kernels import ref as kref
 from repro.kernels.nm_spmm import nm_matmul
 
@@ -31,8 +32,6 @@ LAYERS = {
     "mlp gate/up": (8, 3584, 2 * 18944),
     "mlp down":  (8, 18944, 3584),
 }
-HBM_GBPS = 819.0
-PEAK_FLOPS = 197e12
 
 
 def run(out_rows: list) -> None:
@@ -40,13 +39,14 @@ def run(out_rows: list) -> None:
     print(fmt_row(["module", "dense_MB", "nm_MB", "ratio", "proj_speedup",
                    "kernel_ok"], [12, 10, 10, 8, 12, 9]))
     tot_d = tot_c = 0.0
+    pk = peaks()
     for name, (M, K, N) in LAYERS.items():
         dense_b = K * N * 2                      # bf16 weights
         comp_b = (K // 2) * N * 2 + (K // 2) * N // 4  # vals + 2-bit idx
         act_b = (M * K + M * N) * 2
-        t_dense = (dense_b + act_b) / (HBM_GBPS * 1e9)
-        t_comp = (comp_b + act_b) / (HBM_GBPS * 1e9)
-        t_flops = 2 * M * K * N / PEAK_FLOPS
+        t_dense = (dense_b + act_b) / pk["hbm_bytes_s"]
+        t_comp = (comp_b + act_b) / pk["hbm_bytes_s"]
+        t_flops = 2 * M * K * N / pk["flops_bf16"]
         speed = (max(t_dense, t_flops)) / max(t_comp, t_flops)
         # correctness on the exact (padded) shape
         Kp, Np = K + (-K % 512), N + (-N % 256)

@@ -38,7 +38,7 @@ import jax
 __all__ = ["AuditReport", "audit_jaxpr", "audit_fn", "audit_donation",
            "PSUM_PRIMS", "COLLECTIVE_PRIMS", "CALLBACK_PRIMS"]
 
-# psum shows up as "psum2" when shard_map's check_rep rewrite is active;
+# psum shows up as "psum2" under shard_map's replication-checking rewrite;
 # both normalize to "psum" in reports so contracts survive jax upgrades.
 PSUM_PRIMS = frozenset({"psum", "psum2"})
 COLLECTIVE_PRIMS = PSUM_PRIMS | {
@@ -131,10 +131,17 @@ def audit_jaxpr(jaxpr: Any, *, surface: str = "?",
     def walk(j, in_shard_map: bool, depth: int) -> None:
         if depth > 128:
             return
+        prev_psum = None  # (axes, site) of the eqn before, if a psum
         for eqn in j.eqns:
             name = eqn.primitive.name
             rep.n_eqns += 1
             rep.primitives[name] = rep.primitives.get(name, 0) + 1
+            # jax stages a variadic psum as one eqn per operand: adjacent
+            # psums over the same axes at one site are one collective
+            key = ((tuple(eqn.params.get("axes", ()) or ()), _site_of(eqn))
+                   if name in PSUM_PRIMS else None)
+            same_psum = key is not None and key == prev_psum
+            prev_psum = key
 
             if "callback" in name or name in CALLBACK_PRIMS:
                 cb = eqn.params.get("callback", None)
@@ -143,7 +150,7 @@ def audit_jaxpr(jaxpr: Any, *, surface: str = "?",
                     "callback": repr(cb) if cb is not None else "",
                     "scope": _scope(eqn)})
 
-            if name in COLLECTIVE_PRIMS:
+            if name in COLLECTIVE_PRIMS and not same_psum:
                 canon = "psum" if name in PSUM_PRIMS else name
                 rep.collectives[canon] = rep.collectives.get(canon, 0) + 1
                 if name in PSUM_PRIMS:
@@ -198,9 +205,11 @@ def audit_donation(fn: Callable, args: tuple,
     """Donation effectiveness: declared donations vs XLA's actual aliasing.
 
     Lowers+compiles the surface, parses ``input_output_alias`` out of the
-    compiled HLO, and captures jax's "donated buffers were not usable"
-    warnings.  ``fn`` may already be jit-wrapped (its own donate_argnums
-    win); a bare callable is wrapped here with ``donate_argnums``.
+    compiled HLO, and reports jax's "donated buffers were not usable"
+    warnings - or, where jax compiles without one, the donated buffers
+    missing from the alias table.  ``fn`` may already be jit-wrapped (its
+    own donate_argnums win); a bare callable is wrapped here with
+    ``donate_argnums``.
     """
     from repro.launch.hlo_analysis import parse_input_output_aliases
     jfn = fn if hasattr(fn, "lower") else \
@@ -212,6 +221,12 @@ def audit_donation(fn: Callable, args: tuple,
     aliases = parse_input_output_aliases(compiled.as_text())
     undonated = [str(w.message) for w in wl
                  if "donated" in str(w.message).lower()]
+    if not undonated and len(aliases) < declared:
+        # jax 0.9 compiles an unusable donation without a warning: the
+        # compiled program's alias table is the evidence
+        undonated.append(
+            f"{declared - len(aliases)} of {declared} donated buffers are "
+            "aliased to no output of the compiled program")
     return {"declared": declared, "aliased": len(aliases),
             "aliases": aliases, "undonated_warnings": undonated,
             "platform": jax.default_backend()}

@@ -128,15 +128,17 @@ def _pallas_vmem(eqn) -> PallasCall | None:
     n = 0
     for bm in getattr(gm, "block_mappings", ()) or ():
         shape = getattr(bm, "block_shape", None)
-        sd = getattr(bm, "array_shape_dtype", None)
+        sd = getattr(bm, "array_aval", None)
         if shape is None or sd is None:
             continue
         numel = 1
-        for dim in shape:
-            numel *= dim if isinstance(dim, int) else 1  # mapped dims: 1 row
+        for dim in shape:  # Blocked(n) / Squeezed (1 row) per dim
+            size = getattr(dim, "block_size", dim)
+            numel *= size if isinstance(size, int) else 1
         total += numel * sd.dtype.itemsize
         n += 1
-    name = str(eqn.params.get("name_and_src_info", "pallas_call"))
+    info = getattr(eqn.params.get("jaxpr"), "debug_info", None)
+    name = getattr(info, "func_src_info", None) or "pallas_call"
     return PallasCall(name.split(" ")[0], tuple(getattr(gm, "grid", ()) or ()),
                       total, n)
 

@@ -97,7 +97,7 @@ def check_leaves(cfg, params, rules, *, quiet: bool = True
         d = _axes_size(mesh, k_entry)
         K = leaf.shape[-2]
         group = 8 if leaf.idx_bits == 2 else 4
-        if tag is not None:
+        if tag is not None and tag[-2] is not None:
             counts["k_sharded"] += 1
             # prove the stored planes divide: whole vals rows / idx rows
             # (bytes for packed, groups for int8) per K shard
@@ -145,8 +145,8 @@ def check_leaves(cfg, params, rules, *, quiet: bool = True
 
 
 def _axis_names(names_entry) -> set[str]:
-    """Flat mesh-axis names out of one shard_map in_names/out_names entry
-    (a dict {dim: name-or-tuple} in current jax)."""
+    """Flat mesh-axis names out of one shard_map in_specs/out_specs entry
+    (a PartitionSpec: None, a name or a tuple of names per dim)."""
     out: set[str] = set()
     vals = names_entry.values() if hasattr(names_entry, "values") \
         else names_entry
@@ -188,10 +188,10 @@ def check_psum_axes(jaxpr, *, surface: str = "?") -> tuple[dict, list[dict]]:
             if eqn.primitive.name == "shard_map":
                 counts["shard_maps"] += 1
                 in_axes: set[str] = set()
-                for entry in eqn.params.get("in_names", ()) or ():
+                for entry in eqn.params.get("in_specs", ()):
                     in_axes |= _axis_names(entry)
                 out_axes: set[str] = set()
-                for entry in eqn.params.get("out_names", ()) or ():
+                for entry in eqn.params.get("out_specs", ()):
                     out_axes |= _axis_names(entry)
                 psums: list[tuple] = []
                 for sub in _sub_jaxprs(eqn.params):
@@ -228,6 +228,7 @@ def check_arch(arch: str, *, mesh_shape: tuple | None = (2, 2),
     the psum pass still runs.
     """
     import jax
+    from repro.launch.mesh import make_mesh
     from repro.analysis import surfaces
     from repro.dist.axes import make_rules
     report: dict[str, Any] = {"arch": arch,
@@ -237,7 +238,7 @@ def check_arch(arch: str, *, mesh_shape: tuple | None = (2, 2),
         report.update({"skipped": "single device: no partitioning to check",
                        "findings": [], "clean": True})
         return report
-    mesh = jax.make_mesh(tuple(mesh_shape), ("data", "model"))
+    mesh = make_mesh(tuple(mesh_shape), ("data", "model"))
     rules = make_rules(mesh)
     if sparse:
         # families whose prunable kernels cannot take 2:4 (a reduction dim

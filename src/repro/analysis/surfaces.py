@@ -81,6 +81,7 @@ def serve_surfaces(arch: str = "llama3.2-1b", *,
     XLA_FLAGS env); None audits the single-device engine.
     """
     import jax
+    from repro.launch.mesh import make_mesh
     import jax.numpy as jnp
     from repro.dist.axes import make_rules
     from repro.models import model as M
@@ -94,7 +95,7 @@ def serve_surfaces(arch: str = "llama3.2-1b", *,
         params = M.init_params(cfg, jax.random.key(0))
     rules = None
     if mesh_shape is not None:
-        mesh = jax.make_mesh(tuple(mesh_shape), ("data", "model"))
+        mesh = make_mesh(tuple(mesh_shape), ("data", "model"))
         rules = make_rules(mesh)
     eng = ServeEngine(cfg, params, slots=slots, capacity=capacity,
                       rules=rules)

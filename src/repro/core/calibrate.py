@@ -167,12 +167,18 @@ def run_search(cfg: ModelConfig, pcfg: PruneConfig, params0: PyTree,
     """
     prunable = prunable_map(params0)
     loss_fn = loss_fn or partial(lm_loss, cfg)
-    state = mirror.init_search(params0, jax.random.key(17))
-    if rules is not None:
+    key = jax.random.key(17)
+    if rules is None:
+        state = mirror.init_search(params0, key)
+    else:
+        # built in place on the mesh: initialized on one device first, the
+        # three fp32 trees would have to fit on it whole
         from repro.dist import sharding as sharding_mod
         from repro.models import model as M
-        state = jax.device_put(state, sharding_mod.search_state_sharding(
-            M.param_axes(cfg), state, rules))
+        shapes = jax.eval_shape(mirror.init_search, params0, key)
+        state = jax.jit(mirror.init_search, out_shardings=(
+            sharding_mod.search_state_sharding(M.param_axes(cfg), shapes,
+                                               rules)))(params0, key)
     batches = list(batches)
     chunk = pcfg.scan_chunk if scan_chunk is None else scan_chunk
     chunk = max(int(chunk), 0)

@@ -90,12 +90,42 @@ def normalize_scores(s: jax.Array, how: str) -> jax.Array:
     if how == "mean":
         return s / (jax.lax.stop_gradient(jnp.mean(s)) + 1e-12)
     if how == "median":
-        # jnp.median's quantile->gather lowering is broken in this jaxlib;
-        # sort + static middle index is equivalent for our (flat) use.
         flat = jax.lax.stop_gradient(s.reshape(-1))
-        med = jnp.sort(flat)[flat.size // 2]
+        med = kth_smallest_nonneg(flat, flat.size // 2)
         return s / (med + 1e-12)
     raise ValueError(how)
+
+
+def kth_smallest_nonneg(x: jax.Array, k: int) -> jax.Array:
+    """Exactly ``jnp.sort(x)[k]`` for a flat non-negative float32 ``x``.
+
+    Bisection on the bit pattern, which orders non-negative floats like
+    the values: 31 rounds of a count that reduces like a sum, so a sharded
+    ``x`` is never gathered.  A sort of a stacked (layers, K, N) score leaf
+    copies it whole onto every device and does not fit the four-chip
+    full-depth search.  A -0.0 counts as the smallest value (its bits are
+    negative as int32) and comes back as +0.0, which compares equal.  Where
+    the device compares subnormals as zero (CPU and TPU do), the sort may
+    return a subnormal where this returns +0.0: equal as the device
+    compares them.  The count is int32, so ``x`` must hold fewer than 2**31
+    elements.
+    """
+    if x.size >= 2 ** 31:
+        raise ValueError(
+            f"kth_smallest_nonneg counts in int32: {x.size} elements do "
+            "not fit (split the leaf before normalizing)")
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+
+    def halve(_, lo_hi):
+        lo, hi = lo_hi
+        mid = lo + (hi - lo) // 2
+        enough = jnp.sum(bits <= mid, dtype=jnp.int32) > k
+        return jnp.where(enough, lo, mid + 1), jnp.where(enough, mid, hi)
+
+    # invariant: the answer's bits lie in [lo, hi]; +inf bounds from above
+    lo, _ = jax.lax.fori_loop(0, 31, halve,
+                              (jnp.int32(0), jnp.int32(0x7F800000)))
+    return jax.lax.bitcast_convert_type(lo, jnp.float32)
 
 
 def metric_tree(name: str, params: Any, stats: Any, prunable: Any,

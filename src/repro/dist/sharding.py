@@ -94,7 +94,10 @@ def sparse_component_layout(axes_str: str | None, st: SparseTensor,
     experts) and N keep the dense per-dim divisibility fallback.  The tag
     is ``(site, *entries)`` over the *executed* dims (leading "layers"
     stripped - lax.scan slices it away before dispatch) and is None unless
-    K actually shards.
+    K actually shards or, K replicated, an executed N or expert dim does:
+    the K entry is then None (no psum), and the tag still routes the leaf
+    through a shard_map - on a TPU a Pallas kernel cannot run under the
+    partitioner's automatic sharding.
     """
     from repro.kernels.shard import replicated_forced
     mesh = rules.mesh
@@ -133,14 +136,13 @@ def sparse_component_layout(axes_str: str | None, st: SparseTensor,
                           f"index planes); vals AND idx replicate along K"))
     vals_spec = P(*lead, spec_k, n_keep)
     idx_spec = P(*lead, spec_k, n_keep)
-    tag = None
-    if k_tag is not None:
-        exec_entries = lead[1:] if names[0] == "layers" else lead
-        tag = (_site_for(path),
-               *(e if _axes_size(mesh, e) > 1 else None
-                 for e in exec_entries),
-               k_tag,
-               n_keep if _axes_size(mesh, n_keep) > 1 else None)
+    exec_entries = lead[1:] if names[0] == "layers" else lead
+    tag = (_site_for(path),
+           *(e if _axes_size(mesh, e) > 1 else None for e in exec_entries),
+           k_tag,
+           n_keep if _axes_size(mesh, n_keep) > 1 else None)
+    if all(e is None for e in tag[1:]):
+        tag = None
     return vals_spec, idx_spec, tag
 
 
@@ -208,6 +210,15 @@ def params_sharding(axes_tree: PyTree, shapes_tree: PyTree,
     return tree_map_with_path(leaf, axes_tree, shapes_tree,
                               is_leaf=lambda x: x is None)
 
+
+def init_params_sharded(cfg, key: jax.Array, rules: ShardingRules) -> PyTree:
+    """``models.model.init_params`` built in place on the mesh: each device
+    initializes only its own shards, so no device ever holds the whole
+    fp32 tree (the values equal the unsharded init's)."""
+    from repro.models import model as M
+    sh = params_sharding(M.param_axes(cfg), M.param_shapes(cfg), rules)
+    return jax.jit(M.init_params, static_argnums=0, out_shardings=sh)(
+        cfg, key)
 
 def search_state_sharding(axes_tree: PyTree, state, rules: ShardingRules):
     """NamedSharding tree for a ``core.mirror.SearchState`` on the mesh.

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across JAX versions (TPUCompilerParams <= 0.4.x)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def _prox_kernel(w_ref, o_ref, *, lam, iters, damping):
@@ -57,7 +55,7 @@ def prox24(w: jax.Array, *, lam: float, iters: int = 12,
         in_specs=[pl.BlockSpec((bk, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bk, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((K, N), w.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(w)
@@ -92,7 +90,7 @@ def nm_mask24(s: jax.Array, *, bk: int = 256, bn: int = 512,
         in_specs=[pl.BlockSpec((bk, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bk, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((K, N), jnp.bool_),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(s)

@@ -6,8 +6,13 @@ GEMMs are memory-bound (arithmetic intensity ~ batch << 240 flops/byte), and
 a 2:4 weight stored compressed moves ~9/16 of the dense bf16 bytes
 (values K/2*N*2B + 2-bit packed indices K/8*N*1B vs dense K*N*2B; int8
 indices give the weaker 3/4 fallback).  The kernel streams compressed tiles
-HBM->VMEM, expands them to dense in-register on the VPU (a masked broadcast
-- no gather), and feeds the MXU a normal dense matmul.
+HBM->VMEM and never builds the dense tile: Mosaic refuses the row
+interleave that would need (8-bit iota, 3-D (g, 4, bn) relayouts).  The
+expansion moves to the activation side instead: ``spread_x`` lays x out as
+four (M, K/2) planes, plane q holding the dense column each compressed row
+stands for when its position is q, and the kernel accumulates
+sum_q xq[q] @ (vals where idx == q) - four 2-D dots over the (bk/2, bn)
+value tile, every operand 32-bit or bf16.
 
 Layout: W (K, N) pruned 2:4 along K (the reduction dim).  Compressed:
   vals (K/2, N)  bf16   - the two surviving values per group of 4
@@ -17,12 +22,13 @@ and one of two index layouts, named by the tags in ``sparse.formats``:
                           hold the position of compressed row 4r+j
 
 With LAYOUT_PACKED2 the packed bytes are what streams HBM->VMEM; the 2-bit
-unpack is a bitwise shift/mask on the VPU *after* the copy, so the index
-plane costs K/8*N bytes of bandwidth instead of K/2*N.  The int8 path is
+unpack (a 0/1 row-repeat matmul, then a per-row shift/mask) runs *after*
+the copy, so the index plane costs K/8*N bytes of bandwidth instead of
+K/2*N.  The int8 path is
 kept as a fallback (byte-padded planes, legacy callers).
 
-Block tiling: (bm x bk) @ (bk x bn) with compressed operand tiles
-(bk/2 x bn) vals and (bk/2 x bn | bk/8 x bn) idx; K is the innermost
+Block tiling: (4 x bm x bk/2) activation planes against compressed operand
+tiles (bk/2 x bn) vals and (bk/2 x bn | bk/8 x bn) idx; K is the innermost
 (arbitrary) grid dim accumulating into an f32 VMEM scratch, flushed to the
 output on the last K step.
 
@@ -40,9 +46,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across JAX versions (TPUCompilerParams <= 0.4.x)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 # The index-plane layout tags the kernel dispatches on.  Single source of
 # truth; ``sparse.formats`` re-exports them for the storage side.
 LAYOUT_INT8 = "int8"
@@ -52,11 +55,11 @@ LAYOUT_PACKED2 = "packed2"
 def unpack_idx2(packed: jax.Array) -> jax.Array:
     """(..., rows, n) uint8 packed codes -> (..., rows*4, n) int8 positions.
 
-    The single definition of the 2-bit layout: byte row r carries compressed
-    rows 4r..4r+3 in bit pairs 2j..2j+1.  Used both as the in-kernel VMEM
-    unpack (2-D tile after the HBM->VMEM copy; shift/mask runs on the VPU in
-    int32 lanes, Mosaic's native integer width, then narrows to int8 for the
-    expand compare) and, via ``sparse.formats``, as the host/storage unpack.
+    The storage-side definition of the 2-bit layout: byte row r carries
+    compressed rows 4r..4r+3 in bit pairs 2j..2j+1.  Runs in XLA (host /
+    ``sparse.formats`` unpack); the kernel unpacks the same bytes with the
+    2-D formulation in :func:`_tile_codes`, checked against this one in the
+    tests.
     """
     *lead, rows, n = packed.shape
     p = packed.astype(jnp.int32)
@@ -65,34 +68,64 @@ def unpack_idx2(packed: jax.Array) -> jax.Array:
     return out.reshape(*lead, rows * 4, n).astype(jnp.int8)
 
 
-def _expand_tile(vals, idx):
-    """(bk/2, bn) compressed -> (bk, bn) dense, in-register.
+def _tile_codes(idx, packed: bool, rows: int):
+    """Index tile -> (rows, bn) int32 in-group positions, 2-D and 32-bit.
 
-    Group g occupies dense rows 4g..4g+3; compressed rows 2g, 2g+1 carry
-    (value, position).  dense[4g + r, n] = sum_j vals[2g+j, n] * (idx==r).
+    int8 planes already hold one position per compressed row.  A packed
+    (rows/4, bn) tile is widened to one byte per compressed row with a 0/1
+    row-repeat matmul on the MXU (bytes 0..255 are exact in bf16 and the
+    f32 accumulator), then each row shifts out its own bit pair: row c
+    reads bits 2*(c % 4).  No 8-bit iota and no 3-D reshape, which Mosaic
+    refuses.
     """
-    half, bn = vals.shape
-    g = half // 2
-    v = vals.reshape(g, 2, bn)
-    p = idx.reshape(g, 2, bn)
-    r = jax.lax.broadcasted_iota(jnp.int8, (g, 4, bn), 1)  # in-group row
-    dense = jnp.zeros((g, 4, bn), vals.dtype)
-    for j in range(2):
-        hit = p[:, j:j + 1, :] == r
-        dense = dense + jnp.where(hit, v[:, j:j + 1, :], 0)
-    return dense.reshape(g * 4, bn)
+    if not packed:
+        return idx.astype(jnp.int32)
+    nb = idx.shape[0]
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, nb), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, nb), 1)
+    repeat = jnp.where((r >> 2) == c, 1.0, 0.0).astype(jnp.bfloat16)
+    byte = idx.astype(jnp.int32).astype(jnp.float32).astype(jnp.bfloat16)
+    byte = jnp.dot(repeat, byte,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+    shift = 2 * (jax.lax.broadcasted_iota(jnp.int32, byte.shape, 0) & 3)
+    return (byte >> shift) & 3
 
 
-def _nm_matmul_kernel(x_ref, vals_ref, idx_ref, o_ref, acc_ref, *, nk,
+def _accumulate(acc_ref, xq, vals, codes) -> None:
+    """acc += sum_q xq[q] @ (vals where codes == q).
+
+    ``xq[q]`` (bm, bk/2) holds the activation column each compressed row
+    meets when its position is q (see :func:`spread_x`), so the 2:4
+    expansion happens on the activation side and every operand stays a
+    plain 2-D tile.
+    """
+    vf = vals.astype(jnp.float32)
+    acc = acc_ref[...]
+    for q in range(4):
+        wq = jnp.where(codes == q, vf, 0.0).astype(xq.dtype)
+        acc += jnp.dot(xq[q], wq, preferred_element_type=jnp.float32)
+    acc_ref[...] = acc
+
+
+def spread_x(x: jax.Array) -> jax.Array:
+    """(..., M, K) -> (..., 4, M, K/2): plane q, column c holds
+    x[..., 4 * (c // 2) + q] - the dense row compressed row c stands for
+    when its in-group position is q."""
+    *lead, m, k = x.shape
+    x4 = x.reshape(*lead, m, k // 4, 1, 4)
+    x4 = jnp.broadcast_to(x4, (*lead, m, k // 4, 2, 4))
+    return jnp.moveaxis(x4.reshape(*lead, m, k // 2, 4), -1, -3)
+
+
+def _nm_matmul_kernel(xq_ref, vals_ref, idx_ref, o_ref, acc_ref, *, nk,
                       packed):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    idx = unpack_idx2(idx_ref[...]) if packed else idx_ref[...]
-    dense_w = _expand_tile(vals_ref[...], idx)
-    acc_ref[...] += jnp.dot(x_ref[...], dense_w,
-                            preferred_element_type=jnp.float32)
+    vals = vals_ref[...]
+    codes = _tile_codes(idx_ref[...], packed, vals.shape[0])
+    _accumulate(acc_ref, xq_ref[...], vals, codes)
 
     @pl.when(pl.program_id(2) == nk - 1)
     def _flush():
@@ -160,25 +193,25 @@ def nm_matmul(x: jax.Array, vals: jax.Array, idx: jax.Array, *,
         functools.partial(_nm_matmul_kernel, nk=nk, packed=packed),
         grid=(M // bm, N // bn, nk),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda m, n, k: (m, k)),
+            pl.BlockSpec((4, bm, bk // 2), lambda m, n, k: (0, m, k)),
             pl.BlockSpec((bk // 2, bn), lambda m, n, k: (k, n)),
             pl.BlockSpec((bk // idx_rows, bn), lambda m, n, k: (k, n)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype or x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, vals, idx)
+    )(spread_x(x), vals, idx)
 
 
 # ---------------------------------------------------------------------------
 # Expert-banked variant (MoE)
 # ---------------------------------------------------------------------------
 
-def _nm_matmul_expert_kernel(x_ref, vals_ref, idx_ref, o_ref, acc_ref, *, nk,
-                             packed):
+def _nm_matmul_expert_kernel(xq_ref, vals_ref, idx_ref, o_ref, acc_ref, *,
+                             nk, packed):
     """Same tile math as ``_nm_matmul_kernel``; the grid grew a leading
     expert dim so every ref carries a size-1 expert block (sliced off with
     [0]).  One (bm x bn) f32 accumulator per (e, m, n) program."""
@@ -186,10 +219,9 @@ def _nm_matmul_expert_kernel(x_ref, vals_ref, idx_ref, o_ref, acc_ref, *, nk,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    idx = unpack_idx2(idx_ref[0]) if packed else idx_ref[0]
-    dense_w = _expand_tile(vals_ref[0], idx)
-    acc_ref[...] += jnp.dot(x_ref[0], dense_w,
-                            preferred_element_type=jnp.float32)
+    vals = vals_ref[0]
+    codes = _tile_codes(idx_ref[0], packed, vals.shape[0])
+    _accumulate(acc_ref, xq_ref[0], vals, codes)
 
     @pl.when(pl.program_id(3) == nk - 1)
     def _flush():
@@ -235,7 +267,8 @@ def nm_matmul_expert(x: jax.Array, vals: jax.Array, idx: jax.Array, *,
         functools.partial(_nm_matmul_expert_kernel, nk=nk, packed=packed),
         grid=(E, M // bm, N // bn, nk),
         in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda e, m, n, k: (e, m, k)),
+            pl.BlockSpec((1, 4, bm, bk // 2),
+                         lambda e, m, n, k: (e, 0, m, k)),
             pl.BlockSpec((1, bk // 2, bn), lambda e, m, n, k: (e, k, n)),
             pl.BlockSpec((1, bk // idx_rows, bn),
                          lambda e, m, n, k: (e, k, n)),
@@ -243,8 +276,8 @@ def nm_matmul_expert(x: jax.Array, vals: jax.Array, idx: jax.Array, *,
         out_specs=pl.BlockSpec((1, bm, bn), lambda e, m, n, k: (e, m, n)),
         out_shape=jax.ShapeDtypeStruct((E, M, N), out_dtype or x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(x, vals, idx)
+    )(spread_x(x), vals, idx)

@@ -1,102 +1,28 @@
-"""Public jit'd wrappers over the Pallas kernels (shape padding, tree-level
-application, CPU-interpret fallbacks).
+"""The platforms the kernels run on.
 
-On a real TPU these dispatch to the compiled kernels; on CPU they run in
-interpret mode (bit-accurate against ref.py, validated in tests).
+On a TPU the serving path runs the compiled Pallas kernels; on the CPU the
+pure-jnp references in ``ref.py`` (the kernels themselves are checked
+against those in interpret mode by the tests).  The 2:4 GEMM dispatch is
+``sparse.apply._run_nm``: ``jax.lax.platform_dependent`` picks the branch
+when the program is lowered, and lowering for any other platform is an
+error - a GPU or an unknown plug-in must not slip onto a reference path
+unnoticed.  ``backend()`` serves code that must choose while tracing (an
+algorithm whose collectives differ per platform), never at import.
 """
 from __future__ import annotations
 
-from typing import Any
-
 import jax
-import jax.numpy as jnp
 
-from repro.kernels import ref
-from repro.kernels.flash_decode import (flash_decode, flash_decode_partial,
-                                        flash_decode_partial_ref,
-                                        flash_decode_ref)
-from repro.kernels.nm_prox import nm_mask24, prox24
-from repro.kernels.nm_spmm import nm_matmul
-
-PyTree = Any
-_ON_TPU = jax.default_backend() == "tpu"
+SUPPORTED_PLATFORMS = ("cpu", "tpu")
 
 
-def _interp() -> bool:
-    return not _ON_TPU
+def backend() -> str:
+    """The default backend's platform, "cpu" or "tpu"; anything else is an
+    error (the serving path has kernels for TPU and references for CPU)."""
+    platform = jax.default_backend()
+    if platform not in SUPPORTED_PLATFORMS:
+        raise RuntimeError(
+            f"platform {platform!r} is not supported: the kernels run on "
+            "'tpu' and their references on 'cpu' (set JAX_PLATFORMS)")
+    return platform
 
-
-# --- 2:4 compressed weights ------------------------------------------------
-
-def compress_leaf(w: jax.Array) -> dict:
-    """Dense 2:4-pruned (d_in, d_out) kernel -> compressed {vals, idx}."""
-    vals, idx = ref.compress_24(w)
-    return {"vals": vals.astype(jnp.bfloat16), "idx": idx}
-
-
-def compress_params_24(params: PyTree, masks: PyTree) -> PyTree:
-    """Compress every 2-D masked kernel; other leaves pass through."""
-    def leaf(w, m):
-        if m is None or w.ndim != 2 or w.shape[0] % 4:
-            return w
-        return compress_leaf(w * m.astype(w.dtype))
-
-    return jax.tree.map(leaf, params, masks, is_leaf=lambda x: x is None)
-
-
-def sparse_dense(x: jax.Array, packed: dict, *, bm: int = 128,
-                 bk: int = 512, bn: int = 256) -> jax.Array:
-    """x @ W for a compressed 2:4 weight (kernel on TPU, oracle on CPU)."""
-    if _interp():
-        return ref.nm_matmul_ref(x, packed["vals"], packed["idx"])
-    K2, N = packed["vals"].shape
-    return nm_matmul(x, packed["vals"], packed["idx"], bm=min(bm, x.shape[0]),
-                     bk=min(bk, 2 * K2), bn=min(bn, N))
-
-
-# --- fused mirror-descent elementwise pass ----------------------------------
-
-def fused_mirror_leaf(w, a, gamma, v, *, metric: str, v_lr: float,
-                      lam: float, rowsum=None, colsum=None):
-    from repro.kernels.saliency_fuse import saliency_fused_step
-    if _interp():
-        rs = None if rowsum is None else rowsum[:, None]
-        cs = None if colsum is None else colsum[None, :]
-        if metric == "magnitude":
-            return ref.saliency_step_ref(w, jnp.ones(w.shape[:-1]), gamma, v,
-                                         v_lr=v_lr, lam=lam)
-        return ref.saliency_step_ref(w, a, gamma, v, v_lr=v_lr, lam=lam,
-                                     rowsum=rs, colsum=cs)
-    return saliency_fused_step(w, a, gamma, v, metric=metric, v_lr=v_lr,
-                               lam=lam, rowsum=rowsum, colsum=colsum)
-
-
-# --- decode attention --------------------------------------------------------
-
-def decode_attention(q, k, v, bias, *, scale=None):
-    """(B,K,G,D) x (B,C,K,D) -> (B,K,G,Dv); kernel on TPU, oracle on CPU."""
-    if _interp():
-        return flash_decode_ref(q, k, v, bias, scale=scale)
-    return flash_decode(q, k, v, bias, scale=scale)
-
-
-def decode_attention_partial(q, k, v, bias, *, scale=None):
-    """Un-normalized decode attention over a capacity shard: float32
-    (acc, m, l) partials for the cross-shard pmax/psum combine in
-    ``kernels.shard.decode_attend_sharded``."""
-    if _interp():
-        return flash_decode_partial_ref(q, k, v, bias, scale=scale)
-    return flash_decode_partial(q, k, v, bias, scale=scale)
-
-
-def prox24_op(w: jax.Array, lam: float) -> jax.Array:
-    if _interp():
-        from repro.core.prox import prox_nm24
-        return prox_nm24(w, lam)
-    return prox24(w, lam=lam)
-
-
-def nm_mask24_op(s: jax.Array) -> jax.Array:
-    if _interp():
-        return ref.nm_mask_ref(s)
-    return nm_mask24(s)

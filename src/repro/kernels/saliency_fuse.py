@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across JAX versions (TPUCompilerParams <= 0.4.x)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def _fuse_kernel(w_ref, a_ref, row_ref, col_ref, g_ref, v_ref,
@@ -78,7 +76,7 @@ def saliency_fused_step(w, a, gamma, v, *, metric: str = "wanda",
         ],
         out_shape=[jax.ShapeDtypeStruct((K, N), jnp.float32),
                    jax.ShapeDtypeStruct((K, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(w, a2, row2, col2, gamma, v)

@@ -20,10 +20,14 @@ asserts on) and records ``dist.collective_ms`` on eager calls.
 
 ``decode_attend_sharded`` is the KV-cache sibling: capacity-sharded caches
 run a local flash partial (TPU) or an exact-mimic masked softmax (CPU
-interpret parity), then pmax/psum combine - a sharded fleet member never
-falls back to replicated weights or a replicated cache.
+parity), then pmax/psum combine - a sharded fleet member never falls back
+to replicated weights or a replicated cache.
 
-``REPRO_FORCE_REPLICATED=1`` disables every K-sharded path (tags are not
+Leaves whose K cannot shard but whose N (or expert) dim does carry a tag
+with a None K entry: they run under the same shard_map with no psum, since
+on a TPU a Pallas kernel cannot be partitioned automatically.
+
+``REPRO_FORCE_REPLICATED=1`` disables every K-sharded path (no K entry is
 stamped, caches stay per-GSPMD) - the escape hatch when a mesh/collective
 bug needs bisecting.
 """
@@ -38,7 +42,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro import obs
 from repro.dist.axes import current_rules
-from repro.models import common as cm
 
 FORCE_REPLICATED_ENV = "REPRO_FORCE_REPLICATED"
 
@@ -74,6 +77,19 @@ def k_sharded(st) -> bool:
     if getattr(st, "shard", None) is None or st.k_shard is None:
         return False
     return current_rules() is not None
+
+
+def tp_routed(st) -> bool:
+    """Does this leaf run under a shard_map here?  True when it carries a
+    tensor-parallel tag (K-, N- or expert-sharded) and rules are installed;
+    only a K-sharded tag adds the psum."""
+    return (getattr(st, "shard", None) is not None
+            and current_rules() is not None)
+
+
+def _k_axes(st) -> tuple[str, ...]:
+    """Mesh axes the K-partial psum reduces, () for a K-replicated leaf."""
+    return _ax_tuple(st.shard[-2]) if k_sharded(st) else ()
 
 
 def pair_k_sharded(st_a, st_b) -> bool:
@@ -127,15 +143,18 @@ def _local_nm(x, vals, idx, expert: bool = False):
 # ---------------------------------------------------------------------------
 
 def nm_dense_sharded(st, x2: jax.Array, *, site: str) -> jax.Array:
-    """x2 (M, K) @ K-sharded compressed (K, N) -> (M, N); one psum."""
+    """x2 (M, K) @ tagged compressed (K, N) -> (M, N); one psum when K is
+    sharded, none when only N is."""
     rules = current_rules()
     mesh = rules.mesh
-    k_e, n_e = st.shard[-2], st.shard[-1]
-    k_axes = _ax_tuple(k_e)
+    k_axes = _k_axes(st)
+    k_e = k_axes or None
+    n_e = st.shard[-1]
     out_dt = x2.dtype
     M = x2.shape[0]
     n_loc = st.shape[-1] // axes_size(mesh, n_e)
-    _count(site, M * n_loc * 4)
+    if k_axes:
+        _count(site, M * n_loc * 4)
     idx_plane = st.idx if st.kernel_layout == "packed2" else st.unpacked_idx()
 
     def local(xl, vl, il):
@@ -143,11 +162,13 @@ def nm_dense_sharded(st, x2: jax.Array, *, site: str) -> jax.Array:
         # auditor attributes collectives per site without running anything
         with jax.named_scope(f"site:{site}"):
             y = _local_nm(xl, vl, il)
-            return jax.lax.psum(y, k_axes).astype(out_dt)
+            if k_axes:
+                y = jax.lax.psum(y, k_axes)
+            return y.astype(out_dt)
 
-    f = cm.shard_map(local, mesh=mesh,
-                     in_specs=(P(None, k_e), P(k_e, n_e), P(k_e, n_e)),
-                     out_specs=P(None, n_e), check_rep=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(P(None, k_e), P(k_e, n_e), P(k_e, n_e)),
+                      out_specs=P(None, n_e), check_vma=False)
     return _timed(site, _eager(x2), f, x2, st.vals.astype(out_dt), idx_plane)
 
 
@@ -175,10 +196,10 @@ def nm_dense2_sharded(st_a, st_b, x2: jax.Array, *, site: str
             ya, yb = jax.lax.psum((ya, yb), k_axes)
             return ya.astype(out_dt), yb.astype(out_dt)
 
-    f = cm.shard_map(local, mesh=mesh,
-                     in_specs=(P(None, k_e), P(k_e, n_a), P(k_e, n_a),
-                               P(k_e, n_b), P(k_e, n_b)),
-                     out_specs=(P(None, n_a), P(None, n_b)), check_rep=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(P(None, k_e), P(k_e, n_a), P(k_e, n_a),
+                                P(k_e, n_b), P(k_e, n_b)),
+                      out_specs=(P(None, n_a), P(None, n_b)), check_vma=False)
     return _timed(site, _eager(x2), f, x2, st_a.vals.astype(out_dt), ia,
                   st_b.vals.astype(out_dt), ib)
 
@@ -188,32 +209,36 @@ def nm_dense2_sharded(st_a, st_b, x2: jax.Array, *, site: str
 # ---------------------------------------------------------------------------
 
 def nm_moe_sharded(st, x3: jax.Array, *, site: str = "moe") -> jax.Array:
-    """x3 (E, M, K) @ K-sharded expert bank (E, K, N) -> (E, M, N).
+    """x3 (E, M, K) @ tagged expert bank (E, K, N) -> (E, M, N).
 
     The expert grid rides inside ONE shard_map: every expert's partial comes
     out of a single ``nm_matmul_expert`` call and one psum combines the
-    whole bank - not one collective per expert.
+    whole bank - not one collective per expert (none when K replicates).
     """
     rules = current_rules()
     mesh = rules.mesh
-    e_e, k_e, n_e = st.shard[-3], st.shard[-2], st.shard[-1]
-    k_axes = _ax_tuple(k_e)
+    e_e, n_e = st.shard[-3], st.shard[-1]
+    k_axes = _k_axes(st)
+    k_e = k_axes or None
     out_dt = x3.dtype
     E, M, _ = x3.shape
     e_loc = E // axes_size(mesh, e_e)
     n_loc = st.shape[-1] // axes_size(mesh, n_e)
-    _count(site, e_loc * M * n_loc * 4)
+    if k_axes:
+        _count(site, e_loc * M * n_loc * 4)
     idx_plane = st.idx if st.kernel_layout == "packed2" else st.unpacked_idx()
 
     def local(xl, vl, il):
         with jax.named_scope(f"site:{site}"):
             y = _local_nm(xl, vl, il, expert=True)
-            return jax.lax.psum(y, k_axes).astype(out_dt)
+            if k_axes:
+                y = jax.lax.psum(y, k_axes)
+            return y.astype(out_dt)
 
-    f = cm.shard_map(local, mesh=mesh,
-                     in_specs=(P(e_e, None, k_e), P(e_e, k_e, n_e),
-                               P(e_e, k_e, n_e)),
-                     out_specs=P(e_e, None, n_e), check_rep=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(P(e_e, None, k_e), P(e_e, k_e, n_e),
+                                P(e_e, k_e, n_e)),
+                      out_specs=P(e_e, None, n_e), check_vma=False)
     return _timed(site, _eager(x3), f, x3, st.vals.astype(out_dt), idx_plane)
 
 
@@ -244,12 +269,12 @@ def nm_moe2_sharded(st_up, st_gate, x3: jax.Array, *, site: str = "moe"
             h, g = jax.lax.psum((h, g), k_axes)
             return h.astype(out_dt), g.astype(out_dt)
 
-    f = cm.shard_map(local, mesh=mesh,
-                     in_specs=(P(e_e, None, k_e), P(e_e, k_e, n_u),
-                               P(e_e, k_e, n_u), P(e_e, k_e, n_g),
-                               P(e_e, k_e, n_g)),
-                     out_specs=(P(e_e, None, n_u), P(e_e, None, n_g)),
-                     check_rep=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(P(e_e, None, k_e), P(e_e, k_e, n_u),
+                                P(e_e, k_e, n_u), P(e_e, k_e, n_g),
+                                P(e_e, k_e, n_g)),
+                      out_specs=(P(e_e, None, n_u), P(e_e, None, n_g)),
+                      check_vma=False)
     return _timed(site, _eager(x3), f, x3, st_up.vals.astype(out_dt), iu,
                   st_gate.vals.astype(out_dt), ig)
 
@@ -285,7 +310,7 @@ def decode_attend_sharded(qg: jax.Array, cache_k: jax.Array,
     ``axes``; ok (B, C) valid-slot mask (position + window, precomputed by
     the caller so both paths mask identically).
 
-    CPU (interpret) path mimics the replicated einsum element-for-element:
+    CPU path mimics the replicated einsum element-for-element:
     local scores, global max via pmax, exp/sum, the same
     ``(p / l).astype(v.dtype)`` cast the oracle makes *before* the PV
     einsum, then a psum of the f32 PV partials - token parity with the
@@ -293,13 +318,14 @@ def decode_attend_sharded(qg: jax.Array, cache_k: jax.Array,
     and combines (l, acc) with ONE variadic psum after an m-pmax.
     """
     from repro.kernels import ops
+    from repro.kernels.flash_decode import flash_decode_partial
     rules = current_rules()
     mesh = rules.mesh
     B, Kh, G, _ = qg.shape
     Dv = cache_v.shape[-1]
     NEG = -1e30  # attention.NEG_INF: both paths mask with the same constant
 
-    if ops._interp():
+    if ops.backend() == "cpu":
         # exact-mimic combine: 1 pmax + 2 psums
         _count("attn_kv", B * Kh * G * (1 + Dv) * 4, n_psum=2)
 
@@ -322,17 +348,17 @@ def decode_attend_sharded(qg: jax.Array, cache_k: jax.Array,
         def local(q, ck, cv, okl):
             with jax.named_scope("site:attn_kv"):
                 bias = jnp.where(okl, 0.0, NEG).astype(jnp.float32)
-                acc, m, l = ops.decode_attention_partial(q, ck, cv, bias,
-                                                         scale=scale)
+                acc, m, l = flash_decode_partial(q, ck, cv, bias,
+                                                 scale=scale)
                 mg = jax.lax.pmax(m, axes)
                 corr = jnp.exp(m - mg)
                 l, acc = jax.lax.psum((l * corr, acc * corr), axes)
                 return (acc / jnp.maximum(l, 1e-30)).astype(qg.dtype)
 
     ax = axes[0] if len(axes) == 1 else axes
-    f = cm.shard_map(local, mesh=mesh,
-                     in_specs=(P(None, None, None, None),
-                               P(None, ax, None, None),
-                               P(None, ax, None, None), P(None, ax)),
-                     out_specs=P(None, None, None, None), check_rep=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(P(None, None, None, None),
+                                P(None, ax, None, None),
+                                P(None, ax, None, None), P(None, ax)),
+                      out_specs=P(None, None, None, None), check_vma=False)
     return _timed("attn_kv", _eager(qg), f, qg, cache_k, cache_v, ok)

@@ -27,6 +27,7 @@ from typing import Any
 import jax
 
 from repro import obs
+from repro.launch import compile_cache
 from repro.configs.base import PruneConfig, get_config, get_smoke_config
 
 PyTree = Any
@@ -146,6 +147,7 @@ def main(argv=None) -> None:
                     help="capture a jax profiler trace here, with "
                          "StepTraceAnnotation marks per pipeline stage")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.trace_dir:
         obs.configure(trace_dir=args.trace_dir)
@@ -153,7 +155,6 @@ def main(argv=None) -> None:
     from repro.data.synthetic import batches_for
     from repro.models import model as M
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    params = M.init_params(cfg, jax.random.key(0))
     calib = batches_for(cfg, n=args.calib_n, batch=args.batch, seq=args.seq,
                         split="calib")
     pcfg = PruneConfig(local_metric=args.metric, mode=args.mode,
@@ -162,9 +163,13 @@ def main(argv=None) -> None:
                        grad_accum=args.grad_accum)
     rules = None
     if args.mesh == "host":
-        from repro.dist.sharding import make_production_rules
+        from repro.dist.sharding import (init_params_sharded,
+                                         make_production_rules)
         from repro.launch.mesh import make_host_mesh
         rules = make_production_rules(make_host_mesh())
+        params = init_params_sharded(cfg, jax.random.key(0), rules)
+    else:
+        params = M.init_params(cfg, jax.random.key(0))
 
     if args.xprof_dir:
         jax.profiler.start_trace(args.xprof_dir)

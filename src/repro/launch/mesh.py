@@ -1,22 +1,35 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 Single pod: (data=16, model=16) = 256 chips (TPU v5e pod).
 Multi-pod:  (pod=2, data=16, model=16) = 512 chips; the pod axis is pure
 data/FSDP parallelism over DCI.
+
+Every mesh is built with Auto axes: the model places arrays through
+``NamedSharding`` and ``with_sharding_constraint`` and leaves the rest to
+the partitioner (``jax.make_mesh`` defaults to Explicit axes, under which
+an ambiguous gather such as the embedding lookup is a type error).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis Auto."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Small mesh over whatever devices exist (tests / smoke runs)."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

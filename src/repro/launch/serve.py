@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.launch import compile_cache
 from repro.configs.base import PruneConfig, get_config, get_smoke_config
 from repro.data.synthetic import batches_for
 from repro.models import model as M
@@ -227,6 +228,7 @@ def main(argv=None) -> None:
                          "StepTraceAnnotation marks per prefill/decode "
                          "step")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.trace_dir:
         obs.configure(trace_dir=args.trace_dir)

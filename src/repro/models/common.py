@@ -23,13 +23,6 @@ PyTree = Any
 COMPUTE_DTYPE = jnp.bfloat16
 PARAM_DTYPE = jnp.float32
 
-# shard_map was promoted out of experimental in jax 0.5.x; 0.4.x only has
-# the old path.  Shared here so every call site (moe dispatch/combine,
-# slstm scan) resolves the same symbol.
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
 
 class Builder:
     """Single-definition parameter structure builder."""

@@ -119,7 +119,7 @@ def moe_apply(p: PyTree, x: jax.Array, *, top_k: int,
 
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
-        dispatch_local = cm.shard_map(
+        dispatch_local = jax.shard_map(
             dispatch_local, mesh=mesh,
             in_specs=(P(batch_axes, None, None), P(batch_axes, None)),
             out_specs=(P(batch_axes, None, None, None), P(batch_axes, None),
@@ -171,7 +171,7 @@ def moe_apply(p: PyTree, x: jax.Array, *, top_k: int,
 
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
-        combine_local = cm.shard_map(
+        combine_local = jax.shard_map(
             combine_local, mesh=mesh,
             in_specs=(P(batch_axes, None, None, None), P(batch_axes, None),
                       P(batch_axes, None), P(batch_axes, None),

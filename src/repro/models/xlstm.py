@@ -20,18 +20,6 @@ from repro.models.common import Builder
 PyTree = Any
 
 
-def _pvary(x, axis_names):
-    """Device-varying marker for replicated operands under shard_map.
-
-    jax >= 0.6 requires an explicit ``pvary`` before mixing a replicated
-    operand into device-varying compute; 0.4.x has no such primitive and
-    its shard_map rep-checker handles replicated operands implicitly, so
-    the identity is the correct (and only) fallback there.
-    """
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, axis_names) if fn is not None else x
-
-
 # ---------------------------------------------------------------------------
 # mLSTM
 # ---------------------------------------------------------------------------
@@ -253,7 +241,8 @@ def _slstm_step(carry, g_t, r, num_heads):
 def _slstm_scan(gates_in, state_tuple, r, num_heads, axis_names):
     """Sequential sLSTM scan with hand-written BPTT.
 
-    Plain autodiff-of-scan under shard_map transposes the per-step `pvary`
+    Plain autodiff-of-scan under shard_map transposes the per-step varying
+    cast
     of the replicated recurrent weight R into a per-timestep psum of dR
     (4.7 MB x seq_len x layers - the xlstm train collective bottleneck).
     The custom VJP accumulates dR locally in the reverse scan's carry and
@@ -268,7 +257,8 @@ def _slstm_fwd(gates_in, state_tuple, r, num_heads, axis_names):
     d = d4 // 4
     rf = r.astype(jnp.float32)
     if axis_names:  # shard_map: make R device-varying ONCE so its per-step
-        rf = _pvary(rf, axis_names)  # cotangents stay local
+        # cotangents stay local
+        rf = jax.lax.pcast(rf, axis_names, to="varying")
     gates_seq = gates_in.astype(jnp.float32).transpose(1, 0, 2)
 
     def step(carry, g_t):
@@ -287,12 +277,12 @@ def _slstm_bwd(num_heads, axis_names, res, cots):
     d = d4 // 4
     rf = r.astype(jnp.float32)
     if axis_names:
-        rf = _pvary(rf, axis_names)
+        rf = jax.lax.pcast(rf, axis_names, to="varying")
     dh_seq = dh_out.reshape(B, S, num_heads, d // num_heads) \
         .transpose(1, 0, 2, 3).astype(jnp.float32)
     dR0 = jnp.zeros(r.shape, jnp.float32)
     if axis_names:
-        dR0 = _pvary(dR0, axis_names)
+        dR0 = jax.lax.pcast(dR0, axis_names, to="varying")
 
     def back(carry, xs):
         dstate, dR = carry
@@ -348,9 +338,9 @@ def slstm_core(p: PyTree, gates_in: jax.Array, state: PyTree, *,
     if wrap is not None:
         from jax.sharding import PartitionSpec as P
         bsp = P(axis_names, None, None)
-        fn = cm.shard_map(core_fn, mesh=wrap,
-                           in_specs=(bsp, (bsp,) * 4, P(None, None, None)),
-                           out_specs=(bsp, (bsp,) * 4))
+        fn = jax.shard_map(core_fn, mesh=wrap,
+                            in_specs=(bsp, (bsp,) * 4, P(None, None, None)),
+                            out_specs=(bsp, (bsp,) * 4))
     h, (c, n, m, h_last) = fn(gates_in.astype(jnp.float32), init,
                               p["r"]["kernel"])
     return h, {"c": c, "n": n, "m": m, "h": h_last}

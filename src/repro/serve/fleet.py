@@ -187,10 +187,14 @@ class SparsityFleet:
 
     @classmethod
     def from_artifact(cls, bank_dir, params0: PyTree, budgets: Iterable,
-                      **kw) -> "SparsityFleet":
-        """One artifact -> N budget engines (no re-calibration)."""
+                      *, cfg=None, **kw) -> "SparsityFleet":
+        """One artifact -> N budget engines (no re-calibration).
+
+        cfg: the ModelConfig the bank was calibrated for when it is not the
+        registry's (e.g. a depth-cut config), as ``MaskBank.load`` takes.
+        """
         from repro.sparse.bank import MaskBank
-        return cls(MaskBank.load(bank_dir), params0, budgets, **kw)
+        return cls(MaskBank.load(bank_dir, cfg=cfg), params0, budgets, **kw)
 
     # -- per-budget weights --------------------------------------------------
 

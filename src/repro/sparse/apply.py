@@ -2,18 +2,20 @@
 
 ``models.common.dense`` dispatches on leaf type, so a params tree whose
 prunable kernels were replaced by :func:`sparsify_params` serves through the
-compressed kernel (Pallas on TPU, interpret mode on CPU) while every dense
-leaf keeps the existing path.  MoE expert banks (E, d_in, d_out) dispatch
-the same way through ``models.common.expert_dense`` ->
+compressed kernel (Pallas on TPU, a decompress-and-dot reference on CPU)
+while every dense leaf keeps the existing path.  MoE expert banks
+(E, d_in, d_out) dispatch the same way through ``models.common.expert_dense`` ->
 :func:`sparse_moe_dense`, which consumes the dispatch buffer (G, E, C, d)
 directly against the expert-grid kernel ``nm_matmul_expert``.  The leaf's ``kernel_layout`` tag decides what
 the kernel streams: 2-bit-packed index planes (K % 8 == 0) go to the kernel
 *as stored* - the unpack happens inside the kernel after the HBM->VMEM copy,
 so there is no host-side ``unpacked_idx()`` round-trip on the serving path.
 Byte-padded planes (K % 8 != 0) and int8 storage take the int8 fallback.
-On CPU the whole GEMM runs as a single tile (interpret mode has no VMEM
-limit), which keeps the accumulation order identical to XLA's dense bf16
-dot - sparse serving reproduces masked-dense serving token-for-token.
+On CPU the GEMM decompresses and runs one dense dot, which keeps the
+accumulation order identical to XLA's dense bf16 dot - sparse serving
+reproduces masked-dense serving token-for-token.  The branch is chosen when
+the program is lowered for its platform (``jax.lax.platform_dependent``);
+any platform but CPU or TPU is an error.
 """
 from __future__ import annotations
 
@@ -23,8 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.nm_spmm import (LAYOUT_INT8, LAYOUT_PACKED2, nm_matmul,
-                                   nm_matmul_expert)
+from repro.kernels.nm_spmm import (LAYOUT_PACKED2, nm_matmul,
+                                   nm_matmul_expert, unpack_idx2)
 from repro.sparse import pack as pack_mod
 from repro.sparse.formats import SparseTensor
 
@@ -44,25 +46,60 @@ def _largest_block(dim: int, cap: int, mult: int = 1) -> int:
     return dim  # dim < mult: single block
 
 
+def _nm_reference(x: jax.Array, vals: jax.Array, idx: jax.Array,
+                  layout: str, out_dtype=None) -> jax.Array:
+    """CPU execution: decompress and run one dense dot per GEMM.
+
+    The dense weight is exact (2:4 placement of the stored values), and one
+    fp32-accumulated dot keeps XLA's dense contraction order, so sparse
+    serving on the CPU reproduces masked-dense serving token-for-token.
+    """
+    from repro.kernels import ref
+    if layout == LAYOUT_PACKED2:
+        idx = unpack_idx2(idx)
+    decompress = ref.decompress_24
+    for _ in range(vals.ndim - 2):
+        decompress = jax.vmap(decompress)
+    w = decompress(vals, idx).astype(x.dtype)
+    if x.ndim == 2:
+        y = jnp.dot(x, w, preferred_element_type=jnp.float32)
+    else:
+        y = jnp.einsum("emk,ekn->emn", x, w,
+                       preferred_element_type=jnp.float32)
+    return y.astype(out_dtype or x.dtype)
+
+
 def _run_nm(x: jax.Array, vals: jax.Array, idx: jax.Array, layout: str,
             kernel=nm_matmul, out_dtype=None) -> jax.Array:
-    """Pick block sizes and dispatch: x (M, K) through ``nm_matmul`` or,
-    with ``kernel=nm_matmul_expert``, a per-expert batch (E, M, K) through
-    the expert-grid kernel (block selection only sees the trailing dims)."""
-    m, k = x.shape[-2:]
-    n = vals.shape[-1]
-    if jax.default_backend() == "tpu":
-        bn = (_largest_block(n, 256, 128) if n % 128 == 0
-              else _largest_block(n, 256))
-        # packed tiles must cover whole index bytes (8 dense rows/byte row)
-        bk_mult = 8 if layout == LAYOUT_PACKED2 else 4
-        return kernel(x, vals, idx, bm=_largest_block(m, 128),
-                      bk=_largest_block(k, 512, bk_mult), bn=bn,
-                      layout=layout, out_dtype=out_dtype)
-    # interpret mode: one tile (per expert) = one fp32 dot, bit-matching the
-    # dense path's contraction
-    return kernel(x, vals, idx, bm=m, bk=k, bn=n, layout=layout,
-                  interpret=True, out_dtype=out_dtype)
+    """x (M, K) through ``nm_matmul`` or, with ``kernel=nm_matmul_expert``,
+    a per-expert batch (E, M, K) through the expert-grid kernel.
+
+    ``jax.lax.platform_dependent`` picks the branch when the program is
+    lowered: the Pallas kernel for a TPU, :func:`_nm_reference` for the
+    CPU, and an error for any other platform.  Kernel blocks follow the TPU
+    (8, 128) rule: each block dim is the whole array dim or a multiple of
+    the tile.  An M above 128 that 8 does not divide is zero-padded to the
+    next multiple of 8 and the pad rows dropped.
+    """
+    def on_tpu(x, vals, idx):
+        m, k = x.shape[-2:]
+        n = vals.shape[-1]
+        pad = -m % 8 if m > 128 else 0
+        if pad:
+            x = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, pad), (0, 0)])
+        bm = m if m <= 128 else _largest_block(m + pad, 128, 8)
+        # vals tiles are (bk/2, bn) and packed index tiles (bk/8, bn): bk a
+        # multiple of 256 keeps both on the tiling, else the whole K
+        bk = _largest_block(k, 512, 256) if k % 256 == 0 else k
+        bn = _largest_block(n, 256, 128) if n % 128 == 0 else n
+        y = kernel(x, vals, idx, bm=bm, bk=bk, bn=bn, layout=layout,
+                   out_dtype=out_dtype)
+        return y[..., :m, :] if pad else y
+
+    def on_cpu(x, vals, idx):
+        return _nm_reference(x, vals, idx, layout, out_dtype)
+
+    return jax.lax.platform_dependent(x, vals, idx, cpu=on_cpu, tpu=on_tpu)
 
 
 def _kernel_operand(st: SparseTensor) -> tuple[jax.Array, str]:
@@ -78,11 +115,11 @@ def _kernel_operand(st: SparseTensor) -> tuple[jax.Array, str]:
 
 
 def _tp(st: SparseTensor) -> bool:
-    """Route through the shard-mapped K-partial kernels?  True when the
-    leaf carries a K-shard tag (``dist.sharding.tag_compressed``) and rules
-    are installed at trace time (``serve.engine.EngineFns(rules=...)``)."""
-    from repro.kernels.shard import k_sharded
-    return k_sharded(st)
+    """Route through the shard-mapped kernels?  True when the leaf carries
+    a tensor-parallel tag (``dist.sharding.tag_compressed``) and rules are
+    installed at trace time (``serve.engine.EngineFns(rules=...)``)."""
+    from repro.kernels.shard import tp_routed
+    return tp_routed(st)
 
 
 def sparse_dense(st: SparseTensor, x: jax.Array) -> jax.Array:
@@ -131,39 +168,19 @@ def sparse_dense2(st_a: SparseTensor, st_b: SparseTensor, x: jax.Array
                   ) -> tuple[jax.Array, jax.Array]:
     """Fused pair sharing the reduction dim (gated-MLP up+gate).
 
-    Three routes, decided at trace time:
-
-    * K-shard-tagged pair (``kernels.shard.pair_k_sharded``): two local
-      kernels under one shard_map, ONE deferred variadic psum for the whole
-      projection group.
-    * TPU, untagged: two separate kernel calls (a pre-concat of vals/idx
-      would re-copy the weights every step, costing more HBM traffic than
-      the saved launch).
-    * CPU/interpret, untagged: one kernel pass over [A | B] concatenated
-      along N, then split (per-call overhead dominates there).
+    A K-shard-tagged pair (``kernels.shard.pair_k_sharded``) runs two local
+    kernels under one shard_map with ONE deferred variadic psum for the
+    whole projection group; any other pair is two :func:`sparse_dense`
+    calls (a pre-concat of vals/idx would re-copy the weights every step).
     """
     from repro.kernels import shard as ksh
-    *lead, k = x.shape
-    na, nb = st_a.shape[-1], st_b.shape[-1]
-    x2 = x.reshape(-1, k)
-    if ksh.pair_k_sharded(st_a, st_b):
-        ya, yb = ksh.nm_dense2_sharded(st_a, st_b, x2,
-                                       site=st_a.shard_site)
-        return ya.reshape(*lead, na), yb.reshape(*lead, nb)
-    if jax.default_backend() == "tpu":
+    if not ksh.pair_k_sharded(st_a, st_b):
         return sparse_dense(st_a, x), sparse_dense(st_b, x)
-    vals = jnp.concatenate([st_a.vals, st_b.vals], axis=-1).astype(x.dtype)
-    if (st_a.kernel_layout == LAYOUT_PACKED2
-            and st_b.kernel_layout == LAYOUT_PACKED2):
-        # packed planes share the byte layout along K: concat stays packed
-        idx = jnp.concatenate([st_a.idx, st_b.idx], axis=-1)
-        layout = LAYOUT_PACKED2
-    else:
-        idx = jnp.concatenate(
-            [st_a.unpacked_idx(), st_b.unpacked_idx()], axis=-1)
-        layout = LAYOUT_INT8
-    y = _run_nm(x2, vals, idx, layout)
-    return (y[:, :na].reshape(*lead, na), y[:, na:].reshape(*lead, nb))
+    *lead, k = x.shape
+    ya, yb = ksh.nm_dense2_sharded(st_a, st_b, x.reshape(-1, k),
+                                   site=st_a.shard_site)
+    return (ya.reshape(*lead, st_a.shape[-1]),
+            yb.reshape(*lead, st_b.shape[-1]))
 
 
 def sparse_moe_dense2(st_up: SparseTensor, st_gate: SparseTensor,

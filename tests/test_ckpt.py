@@ -11,6 +11,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.ckpt.straggler import HeartbeatMonitor, plan_recovery
+from repro.launch.mesh import make_mesh
 
 
 def tree():
@@ -61,7 +62,7 @@ def test_resave_same_step(tmp_path):
 def test_restore_with_target_sharding(tmp_path):
     """Elastic restore: leaves are placed with the *target* sharding."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     mgr = CheckpointManager(tmp_path)
     mgr.save(1, tree())
     sh = {"w": NamedSharding(mesh, P("data", None)),

@@ -17,6 +17,46 @@ TINY = ModelConfig(name="t", family="dense", d_model=64, num_layers=2,
                    vocab_size=256)
 
 
+
+@settings(max_examples=20, deadline=None)
+@given(seed=st.integers(0, 10_000), n=st.integers(1, 700),
+       levels=st.sampled_from([0, 2, 5, 1000]),
+       frac=st.floats(0.0, 1.0), subnormal=st.booleans())
+def test_kth_smallest_nonneg_equals_sort(seed, n, levels, frac, subnormal):
+    """The bisection median is the sort's k-th element on non-negative data
+    with ties (few distinct levels), zeros and -0.0: the same value.  With
+    subnormals in the data the two agree as the device compares floats
+    (it compares a subnormal as zero)."""
+    rng = np.random.default_rng(seed)
+    x = np.abs(rng.standard_normal(n)).astype(np.float32)
+    if levels:
+        x = np.round(x * levels) / levels          # ties (and zeros)
+    x[rng.random(n) < 0.2] = 0.0
+    x[rng.random(n) < 0.1] = -0.0
+    if subnormal:
+        x[rng.random(n) < 0.1] = np.float32(1e-40)
+    k = min(int(frac * n), n - 1)
+    got = metrics_mod.kth_smallest_nonneg(jnp.asarray(x), k)
+    want = jnp.sort(jnp.asarray(x))[k]
+    assert bool(got == want), (k, float(got), float(want))
+    if not subnormal:
+        assert float(got) == float(want), (k, float(got), float(want))
+
+
+def test_normalize_scores_median_stacked_leaf_matches_sort():
+    """A stacked (layers, K, N) score leaf is scaled by the sort median of
+    all its elements, bit for bit; a leaf of 2**31 elements is refused."""
+    s = jnp.abs(jax.random.normal(jax.random.key(3), (3, 32, 24)))
+    s = jnp.round(s * 4) / 4                       # ties and zeros
+    flat = s.reshape(-1)
+    want = s / (jnp.sort(flat)[flat.size // 2] + 1e-12)
+    got = metrics_mod.normalize_scores(s, "median")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    big = jax.ShapeDtypeStruct((2, 2 ** 30), jnp.float32)
+    with pytest.raises(ValueError, match="int32"):
+        jax.eval_shape(lambda a: metrics_mod.normalize_scores(a, "median"),
+                       big)
+
 @settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 1000), scale=st.floats(0.1, 10.0))
 def test_metric_scale_behaviour(seed, scale):

@@ -7,11 +7,12 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import get_config, SHAPE_CELLS
 from repro.dist import sharding as shd
 from repro.dist.axes import ShardingRules, make_rules
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_spec_dedupes_repeated_mesh_axes(mesh):
@@ -59,7 +60,7 @@ def mesh22():
     """2x2 multi-device mesh (abstract: spec derivation is pure logic, the
     divisibility checks see real axis sizes > 1)."""
     from jax.sharding import AbstractMesh
-    return AbstractMesh((("data", 2), ("model", 2)))
+    return AbstractMesh((2, 2), ("data", "model"))
 
 
 def test_params_sharding_sparse_leaves_2d_mesh(mesh22):
@@ -152,11 +153,12 @@ def test_sparse_leaf_device_put_multidevice():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         os.environ["JAX_PLATFORMS"] = "cpu"
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.dist import sharding as shd
         from repro.dist.axes import make_rules
         from repro.kernels import ref as kref
         from repro.sparse import pack
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         rules = make_rules(mesh)
         w = jax.random.normal(jax.random.key(0), (64, 64), jnp.float32)
         st = pack.pack_nm(w, kref.nm_mask_ref(w), idx_bits=2)
@@ -177,6 +179,56 @@ def test_sparse_leaf_device_put_multidevice():
                            __import__("pathlib").Path(__file__).parent.parent))
     assert r.returncode == 0 and "ok" in r.stdout, (r.stdout, r.stderr)
 
+
+
+def test_init_params_and_search_state_built_sharded_multidevice():
+    """On a real 2x2 mesh (forced host devices in a subprocess) the params
+    and the mirror-descent state are created already sharded - device 0
+    holds only its shards of W, Gamma and V - and the sharded init equals
+    the unsharded one value for value."""
+    import subprocess
+    import sys
+    import textwrap
+    code = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        import dataclasses
+        import jax, numpy as np
+        from repro.configs.base import PruneConfig, get_smoke_config
+        from repro.core import calibrate as cal
+        from repro.data.synthetic import batches_for
+        from repro.dist import sharding as shd
+        from repro.launch.mesh import make_mesh
+        from repro.models import model as M
+        cfg = get_smoke_config("llama3.2-1b")
+        rules = shd.make_production_rules(make_mesh((2, 2), ("data",
+                                                             "model")))
+        p = shd.init_params_sharded(cfg, jax.random.key(0), rules)
+        ref = M.init_params(cfg, jax.random.key(0))
+        for a, b in zip(jax.tree.leaves(p), jax.tree.leaves(ref)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        want = shd.params_sharding(M.param_axes(cfg), M.param_shapes(cfg),
+                                   rules)
+        for a, sh in zip(jax.tree.leaves(p), jax.tree.leaves(want)):
+            assert a.sharding.is_equivalent_to(sh, a.ndim), (a.sharding, sh)
+        assert any(not a.sharding.is_fully_replicated
+                   for a in jax.tree.leaves(p))
+        pcfg = PruneConfig(local_metric="wanda", mode="nm", steps=1,
+                           scan_chunk=1)
+        calib = batches_for(cfg, n=1, batch=4, seq=16, split="calib")
+        stats = cal.collect_stats(cfg, p, calib, rules=rules)
+        state, _ = cal.run_search(cfg, pcfg, p, calib, stats, rules=rules)
+        for tree in (state.W, state.Gamma, state.V):
+            assert any(a.addressable_shards[0].data.size < a.size
+                       for a in jax.tree.leaves(tree))
+        print("ok")
+    """)
+    env = {**__import__("os").environ, "PYTHONPATH": "src"}
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, cwd=str(
+                           __import__("pathlib").Path(__file__).parent.parent))
+    assert r.returncode == 0 and "ok" in r.stdout, (r.stdout, r.stderr)
 
 def test_all_full_configs_have_valid_stages():
     from repro.models import model as M

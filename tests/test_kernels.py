@@ -59,18 +59,23 @@ def test_nm_matmul_packed2_bit_exact_vs_int8(kn, dtype, seed):
 
 
 def test_nm_matmul_packed2_matches_masked_dense_single_tile():
-    """Interpret-mode single tile (the CPU serving configuration) stays
-    bit-exact vs the masked-dense fp32 dot."""
+    """The CPU serving configuration (``sparse.apply._run_nm``: decompress,
+    one dense dot) stays bit-exact vs the masked-dense fp32 dot; the Pallas
+    kernel, which sums four partial dots, agrees to fp32 rounding."""
+    from repro.sparse.apply import _run_nm
     from repro.sparse.formats import _pack_idx2
     K, N, M = 64, 48, 4
     w = jax.random.normal(jax.random.key(11), (K, N), jnp.float32)
     m = ref.nm_mask_ref(w)
     vals, idx = ref.compress_24(w * m)
     x = 0.1 * jax.random.normal(jax.random.key(12), (M, K), jnp.float32)
-    y = nm_matmul(x, vals, _pack_idx2(idx), bm=M, bk=K, bn=N,
-                  layout="packed2", interpret=True)
     want = jnp.dot(x, w * m, preferred_element_type=jnp.float32)
+    y = _run_nm(x, vals, _pack_idx2(idx), "packed2")
     np.testing.assert_array_equal(np.asarray(y), np.asarray(want))
+    yk = nm_matmul(x, vals, _pack_idx2(idx), bm=M, bk=K, bn=N,
+                   layout="packed2", interpret=True)
+    np.testing.assert_allclose(np.asarray(yk), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_compress_roundtrip_preserves_24_weights():
@@ -177,13 +182,58 @@ def test_flash_decode_matches_ref(seed, dims):
                                atol=2e-5)
 
 
+
+@settings(max_examples=6, deadline=None)
+@given(seed=st.integers(0, 1000),
+       dims=st.sampled_from([(2, 2, 4, 32, 128), (1, 1, 8, 64, 256),
+                             (4, 8, 4, 64, 64)]),
+       shards=st.sampled_from([1, 2, 4]))
+def test_flash_decode_partial_matches_ref(seed, dims, shards):
+    """The partial kernel's raw (acc, m, l) match the materialized oracle on
+    every capacity shard, an all-masked shard included, and the shards
+    combine (pmax on m, sum of rescaled l/acc) into the full attention."""
+    from repro.kernels.flash_decode import (flash_decode_partial,
+                                            flash_decode_partial_ref,
+                                            flash_decode_ref)
+    B, K, G, D, C = dims
+    q = 0.5 * jax.random.normal(jax.random.key(seed), (B, K, G, D))
+    k = 0.5 * jax.random.normal(jax.random.key(seed + 1), (B, C, K, D))
+    v = 0.5 * jax.random.normal(jax.random.key(seed + 2), (B, C, K, D))
+    # valid prefix ends inside the first shard: the later shards of a
+    # multi-shard split are all masked
+    valid = jax.random.randint(jax.random.key(seed + 3), (), 1,
+                               C // shards + 1)
+    bias = jnp.where(jnp.arange(C)[None, :] < valid, 0.0, -1e30) * \
+        jnp.ones((B, 1))
+    cs = C // shards
+    parts = []
+    for i in range(shards):
+        sl = slice(i * cs, (i + 1) * cs)
+        got = flash_decode_partial(q, k[:, sl], v[:, sl], bias[:, sl],
+                                   bc=min(32, cs), interpret=True)
+        want = flash_decode_partial_ref(q, k[:, sl], v[:, sl], bias[:, sl])
+        for g, w, name in zip(got, want, ("acc", "m", "l")):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=2e-4, atol=2e-5, err_msg=name)
+        parts.append(got)
+    mg = jnp.max(jnp.stack([m for _, m, _ in parts]), axis=0)
+    l = sum(l_ * jnp.exp(m - mg) for _, m, l_ in parts)
+    acc = sum(a * jnp.exp(m - mg) for a, m, _ in parts)
+    np.testing.assert_allclose(np.asarray(acc / l),
+                               np.asarray(flash_decode_ref(q, k, v, bias)),
+                               rtol=2e-4, atol=2e-5)
+
 def test_ops_sparse_dense_roundtrip():
-    from repro.kernels import ops
+    """A 2-bit-packed bf16 2:4 leaf through the serving dispatch
+    (``sparse.apply.sparse_dense``) reproduces x @ (W * mask)."""
+    from repro.sparse import apply as apply_mod
+    from repro.sparse.pack import pack_nm
     w = jax.random.normal(jax.random.key(0), (128, 64))
     m = ref.nm_mask_ref(w)
-    packed = ops.compress_leaf(w * m)
+    st = pack_nm(w, m, idx_bits=2, dtype=jnp.bfloat16)
+    assert st.kernel_layout == "packed2"
     x = 0.1 * jax.random.normal(jax.random.key(1), (8, 128))
-    y = ops.sparse_dense(x, packed)
+    y = apply_mod.sparse_dense(st, x)
     np.testing.assert_allclose(
         np.asarray(y, np.float32),
         np.asarray(x @ (w * m).astype(jnp.bfloat16), np.float32),

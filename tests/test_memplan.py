@@ -195,10 +195,10 @@ def test_shardcheck_leaves_and_psums_clean_4dev():
     psum IS flagged (the checker can fail, not just pass)."""
     _run_forced_4dev("""
     import jax
+    from repro.launch.mesh import make_mesh
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from repro.analysis import shardcheck
-    from repro.models.common import shard_map
 
     rep = shardcheck.check_arch("llama3.2-1b", mesh_shape=(2, 2))
     assert rep["clean"], rep["findings"]
@@ -206,10 +206,13 @@ def test_shardcheck_leaves_and_psums_clean_4dev():
     assert lv["sparse_leaves"] == lv["k_sharded"] == 7, lv
     assert lv["replicated_k"] == 0 and rep["surfaces"]["decode"]["psums"] > 0
 
-    # negative control: psum over an axis no input spec partitions
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
-    bad = shard_map(lambda x: jax.lax.psum(x, "model"), mesh=mesh,
-                    in_specs=(P("data"),), out_specs=P("data"))
+    # negative control: psum over an axis no input spec partitions (with
+    # check_vma off, as in kernels/shard.py: with it on, jax folds a psum
+    # of an axis-invariant value into a multiply and no psum is left)
+    mesh = make_mesh((2, 2), ("data", "model"))
+    bad = jax.shard_map(lambda x: jax.lax.psum(x, "model"), mesh=mesh,
+                        in_specs=(P("data"),), out_specs=P("data"),
+                        check_vma=False)
     closed = jax.make_jaxpr(bad)(jnp.ones((4, 8)))
     counts, findings = shardcheck.check_psum_axes(closed, surface="bad")
     assert counts["psums"] == 1

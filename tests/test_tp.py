@@ -20,6 +20,7 @@ from jax.sharding import AbstractMesh
 
 from repro.dist import sharding as shd
 from repro.dist.axes import make_rules, use_rules
+from repro.launch.mesh import make_mesh
 
 
 def _run_forced_4dev(code: str) -> None:
@@ -55,30 +56,37 @@ def _pack(key, shape, idx_bits=2):
 
 def test_tag_compressed_stamps_site_and_k_axis():
     """A K-shardable leaf gets (site, *entries) with the K mesh axis at
-    [-2]; the site comes from the leaf path; an unshardable leaf keeps
-    shard=None and passes through by identity (no spurious retrace)."""
-    rules = make_rules(AbstractMesh((("data", 2), ("model", 2))))
+    [-2]; the site comes from the leaf path.  A leaf whose K cannot shard
+    but whose N does is tagged with a None K entry (it runs under a
+    shard_map with no psum); an unshardable leaf keeps shard=None and
+    passes through by identity (no spurious retrace)."""
+    rules = make_rules(AbstractMesh((2, 2), ("data", "model")))
     good = _pack(0, (64, 64))           # K=64 % (8*2) == 0 on either axis
     bad = _pack(1, (8, 64))             # K=8: no K shard possible
+    none = _pack(6, (8, 63))            # neither K nor N=63 can shard
     tree = {"mlp": {"down": {"kernel": good}},
-            "attn": {"wo": {"kernel": bad}}}
+            "attn": {"wo": {"kernel": bad}, "wq": {"kernel": none}}}
     axes = {"mlp": {"down": {"kernel": "mlp|embed"}},
-            "attn": {"wo": {"kernel": "qkv|embed"}}}
+            "attn": {"wo": {"kernel": "qkv|embed"},
+                     "wq": {"kernel": "qkv|embed"}}}
     out = shd.tag_compressed(axes, tree, rules)
     tag = out["mlp"]["down"]["kernel"].shard
     assert tag == ("mlp", "model", "data")
     assert out["mlp"]["down"]["kernel"].k_shard == "model"
     assert out["mlp"]["down"]["kernel"].shard_site == "mlp"
+    # K replicated, N sharded: tagged for the shard_map, no K axis
+    assert out["attn"]["wo"]["kernel"].shard == ("attn", None, "data")
+    assert out["attn"]["wo"]["kernel"].k_shard is None
     # no warning from the quiet pass, leaf untouched by identity
-    assert out["attn"]["wo"]["kernel"] is bad
-    assert out["attn"]["wo"]["kernel"].shard is None
+    assert out["attn"]["wq"]["kernel"] is none
+    assert out["attn"]["wq"]["kernel"].shard is None
 
 
 def test_tag_compressed_strips_scanned_layers_axis():
     """Scan-stacked leaves (layers, K, N): the tag covers the *executed*
     dims only - lax.scan slices the layers axis away before dispatch, so a
     layers entry in the tag would misalign every executed-dim lookup."""
-    rules = make_rules(AbstractMesh((("data", 2), ("model", 2))))
+    rules = make_rules(AbstractMesh((2, 2), ("data", "model")))
     st = _pack(2, (3, 64, 64))
     out = shd.tag_compressed({"kernel": "layers|embed|mlp"},
                              {"kernel": st}, rules)
@@ -91,7 +99,7 @@ def test_tag_survives_tree_flatten_and_device_put_roundtrip():
     """The tag is static pytree aux: flatten/unflatten preserves it, and
     params_sharding mirrors the input leaf's aux verbatim so a tagged tree
     device_puts against its own sharding tree (treedefs must match)."""
-    rules = make_rules(AbstractMesh((("data", 2), ("model", 2))))
+    rules = make_rules(AbstractMesh((2, 2), ("data", "model")))
     st = _pack(3, (64, 64))
     tagged = shd.tag_compressed({"kernel": "mlp|embed"}, {"kernel": st},
                                 rules)["kernel"]
@@ -109,7 +117,7 @@ def test_k_sharded_gates_on_rules_tag_and_env(monkeypatch):
     from repro.kernels import shard as ksh
     st = _pack(4, (64, 64))
     tagged = st.with_shard(("mlp", "model", None))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     assert not ksh.k_sharded(tagged)            # no rules installed
     with use_rules(make_rules(mesh)):
         assert ksh.k_sharded(tagged)
@@ -125,7 +133,7 @@ def test_divisibility_fallback_is_all_or_nothing_and_loud():
     """K % (group * devices) != 0: BOTH components replicate along K (a
     vals-only K shard feeds no kernel) and the structured warning names the
     leaf path; byte-padded packed planes (K % 8 != 0) never qualify."""
-    rules = make_rules(AbstractMesh((("data", 1), ("model", 4))))
+    rules = make_rules(AbstractMesh((1, 4), ("data", "model")))
     st = _pack(5, (72, 128))            # 72 % 8 == 0 but 72 % 32 != 0
     from jax.sharding import PartitionSpec as P
     with pytest.warns(UserWarning, match="cannot shard over mesh axis"):
@@ -205,6 +213,7 @@ def test_infer_layout_is_shard_local():
 
 _SPARSE_SETUP = """
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs.base import get_smoke_config
     from repro.core import masks as masks_mod, metrics as metrics_mod
     from repro.core.prunable import prunable_map
@@ -245,12 +254,12 @@ def test_tp_token_parity_llama_4dev():
                (np.arange(3, 13) * 7) % cfg.vocab_size]
     want = serve(cfg, sparse, None, prompts)
     for shape in [(1, 4), (2, 2)]:
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_mesh(shape, ("data", "model"))
         got = serve(cfg, sparse, make_rules(mesh), prompts)
         assert got == want, (shape, got, want)
     import os
     os.environ["REPRO_FORCE_REPLICATED"] = "1"
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = make_mesh((1, 4), ("data", "model"))
     got = serve(cfg, sparse, make_rules(mesh), prompts)
     assert got == want, ("forced-replicated", got, want)
     print("ok")
@@ -268,7 +277,7 @@ def test_tp_psum_counters_static_per_decode_trace():
     from repro import obs
     obs.configure(enabled=True)
     cfg, sparse = sparse_smoke("llama3.2-1b")
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     eng = ServeEngine(cfg, sparse, slots=2, capacity=32,
                       rules=make_rules(mesh))
     toks = jnp.zeros((2,), jnp.int32)
@@ -304,7 +313,7 @@ def test_tp_padding_edge_replicates_loudly_and_holds_parity():
     cfg, sparse = sparse_smoke(None, cfg=cfg)
     prompts = [np.arange(1, 9) % cfg.vocab_size]
     want = serve(cfg, sparse, None, prompts)
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = make_mesh((1, 4), ("data", "model"))
     with warnings.catch_warnings(record=True) as rec:
         warnings.simplefilter("always")
         got = serve(cfg, sparse, make_rules(mesh), prompts)
@@ -322,7 +331,7 @@ def test_tp_token_parity_moe_expert_banks_4dev():
     _run_forced_4dev(_SPARSE_SETUP + """
     from repro.dist import sharding as shd
     cfg, sparse = sparse_smoke("mixtral-8x22b")
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = make_mesh((1, 4), ("data", "model"))
     rules = make_rules(mesh)
     tagged = shd.tag_compressed(M.param_axes(cfg), sparse, rules)
     down = None
@@ -342,7 +351,7 @@ def test_tp_token_parity_moe_expert_banks_4dev():
                (np.arange(2, 10) * 5) % cfg.vocab_size]
     want = serve(cfg, sparse, None, prompts)
     for shape in [(1, 4), (2, 2)]:
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_mesh(shape, ("data", "model"))
         got = serve(cfg, sparse, make_rules(mesh), prompts)
         assert got == want, (shape, got, want)
     print("ok")
